@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 
 import numpy as np
 
@@ -85,9 +84,9 @@ def min_device_g() -> int:
     return MIN_DEVICE_G
 
 
-#: profile counters surfaced in the construction bench rows
-PROFILE = {"device_calls": 0, "fallback_calls": 0, "sync_cells": 0,
-           "scan_seconds": 0.0}
+#: device scan launches (``scan_calls_per_build``, the construction
+#: bench rows); launch and sync time are the ``jit.scan.*`` spans
+PROFILE = {"device_calls": 0}
 
 #: guards PROFILE: concurrent builds (core/buildsvc.py thread mode) run
 #: device sessions from worker threads and unlocked ``+=`` drops counts
@@ -102,16 +101,19 @@ def _prof_add(key: str, n) -> None:
 def reset_profile() -> None:
     with _PROF_LOCK:
         for k in PROFILE:
-            PROFILE[k] = 0.0 if k == "scan_seconds" else 0
+            PROFILE[k] = 0
+
+
+def slab_write(buf, slab, idx):
+    """Write one (m, Sb, d) slab into the device grid at tick ``idx``
+    (its XLA module reads ``jit_slab_write`` in a trace)."""
+    return lax.dynamic_update_slice(buf, slab, (0, idx, 0))
 
 
 # eager construct (a lazy ``if X is None`` init races under threads); the
 # builder lambda touches jax only when a key is actually built
 _UPDATE_FNS = kernels._BucketCache(
-    lambda *k: jax.jit(
-        lambda buf, slab, idx: lax.dynamic_update_slice(
-            buf, slab, (0, idx, 0)),
-        donate_argnums=0))
+    lambda *k: jax.jit(slab_write, donate_argnums=0), "slab")
 
 
 def _update_fn(m: int, Sb: int, d: int, Tb: int):
@@ -246,7 +248,6 @@ class DeviceGrid:
                 sp.avail[:, h0 + sp.off : h1 + sp.off, :])
         fn = _update_fn(sp.m, Sb, sp.d, self.Tb)
         self.buf = fn(self.buf, slab, np.int32(u0 - self.base))
-        _prof_add("sync_cells", sp.m * Sb * sp.d)
         if self.s0 >= self.s1:
             self.s0, self.s1 = u0, u1
         else:
@@ -271,36 +272,36 @@ class DeviceGrid:
         actually walked.  The computation captures the grid state at launch
         (jax arrays are immutable), so later commits/restores cannot leak
         into the result; the session's version/edge logic treats the bitmap
-        exactly like a synchronous scan of the same state."""
-        t0 = time.perf_counter()
-        sp = self.space
-        m, T, d = sp.avail.shape
-        g = len(ks)
-        W = phi - plo
-        kmax = int(ks.max())
-        hi_read = min(T, phi + kmax - 1)
-        lo_l = plo - sp.off                  # logical window start
-        hi_l = hi_read - sp.off
-        gb, Lb, Wb = kernels.scan_buckets(g, W, kmax)
-        self.ensure(lo_l, hi_l, slack=Lb)
-        Vs_p = np.full((gb, d), 2.0, dtype=np.float32)
-        Vs_p[:g] = ceil32(np.asarray(Vs))
-        ks_p = np.ones(gb, dtype=np.int32)
-        ks_p[:g] = ks
-        kernels.stat_add("scan_calls")
-        fn = kernels.scan_fn_for(m, d, gb, Lb, Wb, self.Tb)
-        dev = fn(self.buf, np.int32(lo_l - self.base),
-                 np.int32(hi_l - lo_l), Vs_p, ks_p)
+        exactly like a synchronous scan of the same state.  The
+        ``jit.scan.launch`` span covers the slab sync and the enqueue."""
+        with kernels.span("jit.scan.launch"):
+            sp = self.space
+            m, T, d = sp.avail.shape
+            g = len(ks)
+            W = phi - plo
+            kmax = int(ks.max())
+            hi_read = min(T, phi + kmax - 1)
+            lo_l = plo - sp.off                  # logical window start
+            hi_l = hi_read - sp.off
+            gb, Lb, Wb = kernels.scan_buckets(g, W, kmax)
+            self.ensure(lo_l, hi_l, slack=Lb)
+            Vs_p = np.full((gb, d), 2.0, dtype=np.float32)
+            Vs_p[:g] = ceil32(np.asarray(Vs))
+            ks_p = np.ones(gb, dtype=np.int32)
+            ks_p[:g] = ks
+            fn = kernels.scan_fn_for(m, d, gb, Lb, Wb, self.Tb)
+            dev = fn(self.buf, np.int32(lo_l - self.base),
+                     np.int32(hi_l - lo_l), Vs_p, ks_p)
         _prof_add("device_calls", 1)
-        _prof_add("scan_seconds", time.perf_counter() - t0)
         return _DeviceRows(dev, W, m, reverse)
 
 
 class _DeviceRows:
     """Lazy view over an in-flight device scan's (g, Wb, m) result.
 
-    ``row(i)`` blocks on the computation once (first access) and hands out
-    walk-order (W*m,) bitmap rows; unused rows are never transferred."""
+    ``row(i)`` blocks on the computation once (first access, the
+    ``jit.scan.sync`` span) and hands out walk-order (W*m,) bitmap rows;
+    unused rows are never transferred."""
 
     __slots__ = ("dev", "host", "W", "m", "reverse")
 
@@ -313,7 +314,8 @@ class _DeviceRows:
 
     def row(self, i: int) -> np.ndarray:
         if self.host is None:
-            self.host = np.asarray(self.dev)
+            with kernels.span("jit.scan.sync"):
+                self.host = np.asarray(self.dev)
             self.dev = None
         row = self.host[i, : self.W, :]
         if self.reverse:
@@ -390,7 +392,6 @@ class JitBackend(BatchedBackend):
             # outcome-identical numpy fallback: launch overhead beats the
             # tensor work for tiny batches, and the hybrid split below
             # needs at least one peer row (see module docstring)
-            _prof_add("fallback_calls", 1)
             return kernels.scan(space.avail, Vs, ks, plo, phi, reverse)
         # hybrid split: row 0 — the task the session walks immediately —
         # runs through the numpy g=1 fast path so the caller never blocks

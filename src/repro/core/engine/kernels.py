@@ -18,7 +18,9 @@ quietly running something else.  Only an injected ``kernel_impl`` fault
 chain pallas -> xla -> numpy; a genuine exception from an impl
 propagates.  ``active()`` reports what actually runs; ``PROFILE``
 accumulates per-(op, impl) call counts and seconds so benchmarks can
-attribute time to the kernel layer.
+attribute time to the kernel layer.  ``span`` (and ``clock``/``count``
+for stretches too frequent for a trace event) adds the program's own
+spans to the same table, on only while a ``jax.profiler`` trace records.
 
 Exactness contract per op (docs/architecture.md "Kernel layer"):
 
@@ -50,6 +52,7 @@ Exactness contract per op (docs/architecture.md "Kernel layer"):
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -146,6 +149,16 @@ def transfer_add(key: str, n: int) -> None:
         if slot is None:
             slot = PROFILE[key] = [0, 0.0]
         slot[0] += n
+
+
+def _profile_add(key: str, seconds: float) -> None:
+    """Count one call of ``seconds`` under ``PROFILE[key]``."""
+    with _STATS_LOCK:
+        slot = PROFILE.get(key)
+        if slot is None:
+            slot = PROFILE[key] = [0, 0.0]
+        slot[0] += 1
+        slot[1] += seconds
 
 
 #: sticky runtime demotions: op -> impls hit by an injected fault at
@@ -346,7 +359,7 @@ def scan_buckets(g: int, W: int, kmax: int) -> tuple[int, int, int]:
 BUCKET_CAP = int(os.environ.get("REPRO_JIT_BUCKET_CAP", "32"))
 
 #: retrace/eviction accounting for the XLA scan buckets
-XLA_STATS = {"compiles": 0, "evictions": 0, "scan_calls": 0}
+XLA_STATS = {"compiles": 0, "evictions": 0}
 
 
 class _BucketCache:
@@ -357,10 +370,16 @@ class _BucketCache:
     two racing gets could both build — double-counting compiles — or
     corrupt the dict).  Holding the lock across ``build`` also means one
     key compiles once, with late arrivals waiting on the winner.
+
+    ``name`` names the cache's ``xla.load.<name>`` span: on a miss while
+    a trace records, the new function's first call (trace, then compile
+    or load from the persistent cache) runs inside it.
     """
 
-    def __init__(self, build: Callable, cap: int = BUCKET_CAP):
+    def __init__(self, build: Callable, name: str = "bucket",
+                 cap: int = BUCKET_CAP):
         self._build = build
+        self._span_key = f"xla.load.{name}"
         self._cap = cap
         self._fns: dict[tuple, Callable] = {}
         self._lock = threading.Lock()
@@ -374,8 +393,30 @@ class _BucketCache:
                     stat_add("evictions")
                 stat_add("compiles")
                 fn = self._build(*key)
+                if recording():
+                    fn = self._loading(key, fn)
             self._fns[key] = fn      # (re)append = most recently used
             return fn
+
+    def _loading(self, key: tuple, fn: Callable) -> Callable:
+        """``fn`` behind a wrapper whose first call runs in the load span
+        and then puts ``fn`` itself back under ``key``."""
+        pending = [fn]
+
+        def first_call(*args, **kwargs):
+            try:
+                pending.pop()            # atomic: one caller takes the span
+            except IndexError:
+                return fn(*args, **kwargs)
+            try:
+                with span(self._span_key):
+                    return fn(*args, **kwargs)
+            finally:
+                with self._lock:
+                    if self._fns.get(key) is first_call:
+                        self._fns[key] = fn
+
+        return first_call
 
     def __len__(self) -> int:
         return len(self._fns)
@@ -440,7 +481,7 @@ def _build_scan_fn(m: int, d: int, gb: int, Lb: int, Wb: int, Tb: int):
 
 # eagerly constructed (cheap — jitting happens per key inside get): a lazy
 # ``global X; if X is None`` init is a check-then-act race under threads
-_SCAN_FNS = _BucketCache(_build_scan_fn)
+_SCAN_FNS = _BucketCache(_build_scan_fn, "scan")
 
 
 def scan_fn_for(m: int, d: int, gb: int, Lb: int, Wb: int,
@@ -466,7 +507,6 @@ def _scan_xla(avail, Vs, ks, plo, phi, reverse=False):
     Vs_p[:g] = ceil32(np.asarray(Vs))
     ks_p = np.ones(gb, dtype=np.int32)
     ks_p[:g] = ks
-    stat_add("scan_calls")
     fn = scan_fn_for(m, d, gb, Lb, Wb, Lb)   # buffer == window here
     good = np.asarray(fn(jnp.asarray(win_p), np.int32(0), np.int32(L),
                          Vs_p, ks_p))
@@ -550,7 +590,7 @@ def _build_elig_fn(n_dims_key):
     return jax.jit(elig)
 
 
-_ELIG_FNS = _BucketCache(_build_elig_fn)
+_ELIG_FNS = _BucketCache(_build_elig_fn, "elig")
 
 
 def _eligibility_launch_args(avail, demands, fit_dims, rigid_dims,
@@ -599,7 +639,8 @@ def _machines_with_candidates_xla(avail, demands, fit_dims, rigid_dims,
                  dem32.nbytes + thr_fit.nbytes + thr_fung.nbytes
                  + sum(mk.nbytes for mk in masks))
     eligible, any_m = fn(dem32, thr_fit, thr_fung, *masks)
-    eligible, any_m = np.asarray(eligible), np.asarray(any_m)
+    with span("machines_with_candidates.xla.sync"):   # blocks on the device
+        eligible, any_m = np.asarray(eligible), np.asarray(any_m)
     transfer_add("machines_with_candidates.xla.bytes_d2h",
                  eligible.nbytes + any_m.nbytes)
     return eligible, any_m
@@ -846,19 +887,87 @@ def active() -> dict[str, str]:
     return {op: resolve(op)[0] for op in OPS}
 
 
+# -- program spans --------------------------------------------------------
+
+if _HAVE_JAX:
+    _TraceAnnotation = jax.profiler.TraceAnnotation
+    #: True exactly while a jax.profiler session records (spans are on)
+    recording = _TraceAnnotation.is_enabled
+else:  # pragma: no cover
+    def recording() -> bool:
+        return False
+
+
+class _Span:
+    """A recording span: ``repro.<key>`` on the profiler's host plane, on
+    the device trace's clock, and one call of its seconds into
+    ``PROFILE[key]`` at exit."""
+
+    __slots__ = ("key", "_ann", "_t0")
+
+    def __init__(self, key: str, meta: dict):
+        self.key = key
+        self._ann = _TraceAnnotation("repro." + key, **meta)
+
+    def __enter__(self) -> "_Span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        _profile_add(self.key, dt)
+        return False
+
+
+#: the span while nothing records: does nothing, times nothing
+_NO_SPAN = contextlib.nullcontext()
+
+
+def span(key: str, **meta):
+    """Context manager timing one program span under ``PROFILE[key]``.
+
+    On exactly while a ``jax.profiler`` trace records: it then enters a
+    ``TraceAnnotation("repro.<key>", **meta)``, so the span sits in the
+    trace beside the device ops, and counts (1, seconds) at exit.  Off,
+    it costs one ``is_enabled`` call and returns a shared no-op.
+    """
+    if not recording():
+        return _NO_SPAN
+    return _Span(key, meta)
+
+
+def clock() -> float | None:
+    """Start of a counted stretch: ``perf_counter()`` while a trace
+    records, else None.
+
+    For stretches that run thousands of times a second (the service pump
+    and its silence scan), where a span's trace event and objects would
+    slow the traced run itself: `count` then adds the stretch to
+    ``PROFILE`` like a span, and the trace shows it only as part of its
+    caller.
+    """
+    return time.perf_counter() if recording() else None
+
+
+def count(key: str, t0: float | None) -> None:
+    """Count the stretch begun at ``t0 = clock()`` under ``PROFILE[key]``
+    (nothing where ``t0`` is None)."""
+    if t0 is not None:
+        _profile_add(key, time.perf_counter() - t0)
+
+
 def _call_profiled(op: str, impl: str, fn: Callable, *args, **kwargs):
     key = f"{op}.{impl}"
     t0 = time.perf_counter()
     try:
+        if recording():
+            with _TraceAnnotation("repro." + key):
+                return fn(*args, **kwargs)
         return fn(*args, **kwargs)
     finally:
-        dt = time.perf_counter() - t0
-        with _STATS_LOCK:
-            slot = PROFILE.get(key)
-            if slot is None:
-                slot = PROFILE[key] = [0, 0.0]
-            slot[0] += 1
-            slot[1] += dt
+        _profile_add(key, time.perf_counter() - t0)
 
 
 def _profile_calls(key: str) -> int:

@@ -329,8 +329,8 @@ def _caches():
     if _WAVE_FNS is None:
         from . import kernels as K
 
-        _WAVE_FNS = K._BucketCache(_build_wave_fn)
-        _UPD_FNS = K._BucketCache(_build_row_update_fn)
+        _WAVE_FNS = K._BucketCache(_build_wave_fn, "wave")
+        _UPD_FNS = K._BucketCache(_build_row_update_fn, "wave_row")
     return _WAVE_FNS, _UPD_FNS
 
 
@@ -342,7 +342,7 @@ def _pallas_cache():
     if _PALLAS_FNS is None:
         from . import kernels as K
 
-        _PALLAS_FNS = K._BucketCache(_build_pallas_wave_fn)
+        _PALLAS_FNS = K._BucketCache(_build_pallas_wave_fn, "wave_pallas")
     return _PALLAS_FNS
 
 

@@ -348,23 +348,25 @@ class ShardedMatcher:
         timeout, capped-backoff retry, quarantine to the conservative
         all-eligible mask — which is decision-exact by the superset
         argument.  Without either, the healthy fast path below runs
-        unchanged.
+        unchanged.  The ``shard.eligibility`` span covers the whole
+        fan-out the wave waits on, tagged with the wave's index.
         """
         plan = self.plan
         slices = plan.slices()
-        if faults.active_plan() is not None or self._any_quarantined:
-            parts = [self._guarded_launch(s, avail[slices[s]], dem)
-                     for s in range(plan.n_shards)]
-        elif plan.n_shards == 1:
-            return self._launch(0, avail, dem)
-        else:
-            parts = list(self._executor().map(
-                lambda s: self._launch(s, avail[slices[s]], dem),
-                range(plan.n_shards)))
-        if plan.n_shards == 1:
-            return parts[0]
-        eligible = np.concatenate([p[0] for p in parts], axis=1)
-        machine_any = np.concatenate([p[1] for p in parts])
+        with kernels.span("shard.eligibility", wave=self.waves):
+            if faults.active_plan() is not None or self._any_quarantined:
+                parts = [self._guarded_launch(s, avail[slices[s]], dem)
+                         for s in range(plan.n_shards)]
+            elif plan.n_shards == 1:
+                return self._launch(0, avail, dem)
+            else:
+                parts = list(self._executor().map(
+                    lambda s: self._launch(s, avail[slices[s]], dem),
+                    range(plan.n_shards)))
+            if plan.n_shards == 1:
+                return parts[0]
+            eligible = np.concatenate([p[0] for p in parts], axis=1)
+            machine_any = np.concatenate([p[1] for p in parts])
         return eligible, machine_any
 
     # -- deficit bookkeeping --------------------------------------------

@@ -947,11 +947,13 @@ class ClusterSim:
             kprof1 = kernels.profile_snapshot()
             # both heartbeat eligibility ops count: above the auto-promotion
             # threshold the dispatched impl is heartbeat_masks-/mwc-xla and
-            # must stay visible in the bench JSON
+            # must stay visible in the bench JSON; "op.impl" keys only
+            # (a traced run adds spans such as the eligibility sync)
             hb = sum(sec - kprof0.get(key, (0, 0.0))[1]
                      for key, (_calls, sec) in kprof1.items()
                      if key.startswith(("machines_with_candidates.",
-                                        "heartbeat_masks.")))
+                                        "heartbeat_masks."))
+                     and key.count(".") == 1)
             phase_times["heartbeat"] = hb
         sstats = smatcher.stats()
         ap1 = faults.active_plan()

@@ -134,6 +134,8 @@ class SchedulerCore:
                       "lease_reclaims": 0, "stale_done": 0, "beats": 0,
                       "suspects": 0, "losses": 0, "rejoins": 0}
         self._pri_cache: dict[tuple, np.ndarray] = {}
+        #: id of the job whose submit is building (the build span's tag)
+        self._submitting: int | None = None
         self._buildsvc: BuildService | None = None
         if spec.order_fn == "dagps" and (
                 cfg.build_workers is None or cfg.build_workers > 1):
@@ -157,14 +159,15 @@ class SchedulerCore:
                    get_backend(self.cfg.placement_backend).name)
             if self.cfg.schedule_cache and key in self._pri_cache:
                 return self._pri_cache[key]
-            if self._buildsvc is not None:
-                pri = self._buildsvc.submit(
-                    dag, self._build_m(),
-                    backend=self.cfg.placement_backend).result().pri_score
-            else:
-                pri = build_schedule(
-                    dag, self._build_m(),
-                    backend=self.cfg.placement_backend).pri_score
+            with kernels.span("svc.build", job=self._submitting):
+                if self._buildsvc is not None:
+                    pri = self._buildsvc.submit(
+                        dag, self._build_m(),
+                        backend=self.cfg.placement_backend).result().pri_score
+                else:
+                    pri = build_schedule(
+                        dag, self._build_m(),
+                        backend=self.cfg.placement_backend).pri_score
             if self.cfg.schedule_cache:
                 self._pri_cache[key] = pri
             return pri
@@ -188,6 +191,7 @@ class SchedulerCore:
 
     def submit(self, dag: DAG, group: int, now: float) -> int:
         job_id = next(self._job_ids)
+        self._submitting = job_id
         pri = self._make_pri(dag)
         job = JobState(job_id, dag, now, group, pri)
         self.jobs[job_id] = job
@@ -251,7 +255,9 @@ class SchedulerCore:
 
     def check_silence(self, now: float) -> list[Lease]:
         """Advance the suspicion/lost ladder; returns reclaimed leases
-        (the service notifies their agents with revoke messages)."""
+        (the service notifies their agents with revoke messages).
+        Counted as ``svc.check_silence`` (`kernels.clock`)."""
+        t0 = kernels.clock()
         reclaimed: list[Lease] = []
         lost_after, suspect_after = self.cfg.lost_after, self.cfg.suspect_after
         # vectorized prefilter: only machines that cross a rung this call
@@ -279,6 +285,7 @@ class SchedulerCore:
             elif silent + 1e-9 >= suspect_after and not self.suspected[m]:
                 self.suspected[m] = True
                 self.stats["suspects"] += 1
+        kernels.count("svc.check_silence", t0)
         return reclaimed
 
     def wave(self, now: float) -> list[Lease]:
@@ -288,7 +295,8 @@ class SchedulerCore:
         if not self._dirty:
             return []
         self._dirty = False
-        batch = self.pool.refresh()
+        with kernels.span("pool.refresh"):
+            batch = self.pool.refresh()
         if batch is None or len(batch) == 0:
             return []
         matchable = self.registered & ~self.suspected & ~self.lost
@@ -396,7 +404,11 @@ class SchedulerService:
 
         Only channels with work are polled (an idle channel's poll is a
         no-op), in connection order, so the outcome equals polling all.
+        Counted as ``svc.pump.wave`` where the pump ran a wave, else
+        ``svc.pump`` (`kernels.clock`: no trace event of its own).
         """
+        t0 = kernels.clock()
+        waves = self.core.smatcher.waves
         now = self._clock() if now is None else now
         with self._lock:
             conns = sorted(self._active, key=self._order.__getitem__)
@@ -436,6 +448,8 @@ class SchedulerService:
                            job=lease.job, task=lease.task,
                            machine=lease.machine, t=lease.t,
                            expected=lease.expected)
+        kernels.count("svc.pump.wave" if self.core.smatcher.waves != waves
+                      else "svc.pump", t0)
 
     def _handle(self, ch: Channel, msg: wire.Msg, now: float) -> None:
         p = msg.payload
