@@ -10,9 +10,9 @@ the Space's grid:
   * before a scan, only the dirty/unsynced slab is uploaded through a
     donated ``dynamic_update_slice`` — buffers are reused in place;
   * scan shapes are padded to the coarse buckets of
-    ``core/engine/kernels.py`` whose compiled-function cache is bounded
-    (``REPRO_JIT_BUCKET_CAP``) with retraces counted in
-    ``kernels.XLA_STATS``;
+    ``core/engine/kernels.py``; every compiled scan and slab-writer
+    program stays resident (the bucket ladders bound the keys), with
+    builds counted in ``kernels.XLA_STATS``;
   * small batches fall back to the numpy kernel through the dispatch
     layer: below ``MIN_DEVICE_G`` rows the launch overhead exceeds the
     tensor work on CPU hosts, and the fallback is outcome-identical by
@@ -113,16 +113,16 @@ def slab_write(buf, slab, idx):
 # eager construct (a lazy ``if X is None`` init races under threads); the
 # builder lambda touches jax only when a key is actually built
 _UPDATE_FNS = kernels._BucketCache(
-    lambda *k: jax.jit(slab_write, donate_argnums=0), "slab")
+    lambda *k: jax.jit(slab_write, donate_argnums=0), "slab", cap=None)
 
 
 def _update_fn(m: int, Sb: int, d: int, Tb: int):
     """Donated slab writer for one (m, Sb, d, Tb) shape bucket.
 
-    Keyed through the shared bounded cache so these compiles are counted
-    in ``kernels.XLA_STATS`` and capped like the scan buckets (the buffer
-    length Tb is part of the trace signature — both slab and buffer sit
-    on coarse ladders, so the key set stays small).
+    Kept, like the scan buckets, in an uncapped bucket cache whose
+    compiles are counted in ``kernels.XLA_STATS`` (the buffer length Tb is
+    part of the trace signature — both slab and buffer sit on coarse
+    ladders, so the key set stays small and no program is ever dropped).
     """
     return _UPDATE_FNS.get((m, Sb, d, Tb))
 
@@ -352,7 +352,8 @@ class JitBackend(BatchedBackend):
         ``T`` (the Space's physical grid length) predicts the device
         mirror's buffer-length bucket, which is part of the scan's trace
         signature; a later reallocation to a different bucket compiles on
-        demand (counted in ``kernels.XLA_STATS``).
+        demand (counted in ``kernels.XLA_STATS``) and, like every scan
+        program, then stays resident for the life of the process.
         """
         if not _HAVE_JAX:
             return

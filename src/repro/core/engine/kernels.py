@@ -335,7 +335,7 @@ def scan_buckets(g: int, W: int, kmax: int) -> tuple[int, int, int]:
     Lb = Wb plus a two-step duration allowance — SHORT_K covers the
     common case (most stage durations quantize to a few ticks), LONG_K
     the bitmap-path ceiling — a handful of distinct keys per (m, d)
-    pair, so the bounded compile cache never thrashes, while the window
+    pair, so the scan's compile cache holds every one, while the window
     read (the per-launch compute) stays ~30% leaner on typical batches.
     """
     gb = pad8(g)
@@ -353,17 +353,19 @@ def scan_buckets(g: int, W: int, kmax: int) -> tuple[int, int, int]:
     return gb, Lb, Wb
 
 
-#: compiled-kernel bucket cache cap (satellite: bound lru growth); above
-#: the cap the least-recently-used bucket is dropped and recompilation is
-#: counted as a retrace
+#: entry cap of the LRU bucket caches (eligibility, wave); above it the
+#: least-recently-used bucket is dropped and its recompilation counted.
+#: The scan and slab-writer caches take no cap (``cap=None``): their keys
+#: all sit on the program's own ladders, which bound them
 BUCKET_CAP = int(os.environ.get("REPRO_JIT_BUCKET_CAP", "32"))
 
-#: retrace/eviction accounting for the XLA scan buckets
+#: builds ("compiles") and LRU drops ("evictions") of the bucket caches
 XLA_STATS = {"compiles": 0, "evictions": 0}
 
 
 class _BucketCache:
-    """Bounded LRU of jitted functions keyed by static shape buckets.
+    """LRU of jitted functions keyed by static shape buckets, bounded at
+    ``cap`` entries; ``cap=None`` keeps every function it builds.
 
     Thread-safe: concurrent build sessions share these caches, so the
     pop/build/reinsert sequence runs under one per-cache lock (unlocked,
@@ -377,7 +379,7 @@ class _BucketCache:
     """
 
     def __init__(self, build: Callable, name: str = "bucket",
-                 cap: int = BUCKET_CAP):
+                 cap: int | None = BUCKET_CAP):
         self._build = build
         self._span_key = f"xla.load.{name}"
         self._cap = cap
@@ -388,7 +390,7 @@ class _BucketCache:
         with self._lock:
             fn = self._fns.pop(key, None)
             if fn is None:
-                if len(self._fns) >= self._cap:
+                if self._cap is not None and len(self._fns) >= self._cap:
                     self._fns.pop(next(iter(self._fns)))
                     stat_add("evictions")
                 stat_add("compiles")
@@ -430,20 +432,23 @@ def _build_scan_fn(m: int, d: int, gb: int, Lb: int, Wb: int, Tb: int):
     dispatch (an eager host-side slice would synchronize with in-flight
     work and serialize the async session).  The grid length Tb is part of
     the trace signature on purpose: it sits on the coarse allocation
-    ladder and in the cache key, so those compiles are counted and capped
-    like every other bucket.  Ticks at/after ``tlive`` (window-relative)
-    are masked infeasible, which reproduces the numpy kernel's grid-edge
-    truncation bit-for-bit.
+    ladder and in the cache key, so those compiles are counted like every
+    other bucket.  Ticks at/after ``tlive`` (window-relative) are masked
+    infeasible, which reproduces the numpy kernel's grid-edge truncation
+    bit-for-bit.
 
     Run detection is *erosion by doubling* instead of the numpy kernel's
     cumsum+gather: E_{2^j}[t] = "ok for 2^j consecutive ticks from t" is
-    built by log2(kmax) shifted ANDs, and each row's k combines the
-    ladder levels of its set bits at accumulated offsets (one per-row
-    dynamic slice per level).  Pure boolean shifts lower much better on
-    CPU/TPU than a length-L integer scan plus a (g, m, W) gather, and the
-    result is identical: a start is good iff all k ticks are ok.  All
-    comparisons are float32-vs-float32 (demands pre-rounded via
-    ``ceil32``), so the bitmaps are bit-identical to the numpy kernel.
+    built by log2(kmax) shifted ANDs, and each row's run grows by the
+    ladder levels of its set bits, all ok in [t, t + 2^j + r) being
+    E_{2^j}[t] and "all ok in [t + 2^j, t + 2^j + r)".  Every shift is
+    static, and a row's bits only select: a per-row dynamic slice (a
+    gather) in its place compiles to 20-30x the TPU code, megabytes a
+    program.  Pure boolean shifts lower much better on CPU/TPU than a
+    length-L integer scan plus a (g, m, W) gather, and the result is
+    identical: a start is good iff all k ticks are ok.  All comparisons
+    are float32-vs-float32 (demands pre-rounded via ``ceil32``), so the
+    bitmaps are bit-identical to the numpy kernel.
     """
     nbits = max(LONG_K.bit_length(), (Lb - Wb).bit_length())
 
@@ -452,36 +457,39 @@ def _build_scan_fn(m: int, d: int, gb: int, Lb: int, Wb: int, Tb: int):
         ok = (win[None, :, :, :] >= Vs[:, None, None, :]).all(axis=3)
         idx = jnp.arange(Lb, dtype=jnp.int32)
         ok = ok & (idx < tlive)[None, None, :]           # (gb, m, Lb)
+
+        def ahead(x, s, fill):
+            """x[:, :, t + s], reading the row's ``fill`` past the end."""
+            tail = jnp.broadcast_to(fill, (gb, m, min(s, Lb)))
+            return jnp.concatenate([x[:, :, s:], tail], axis=2)
+
         # erosion ladder: E[j][t] == all ok in [t, t + 2^j)
+        never = jnp.zeros((gb, 1, 1), dtype=bool)
         E = [ok]
         for j in range(nbits - 1):
-            s = 1 << j
-            prev = E[-1]
-            E.append(prev[:, :, : prev.shape[2] - s] & prev[:, :, s:])
-        acc = jnp.ones((gb, m, Wb), dtype=bool)
-        off = jnp.zeros((gb,), dtype=jnp.int32)
-
-        def row_slice(e, o):
-            return lax.dynamic_slice(e, (0, o), (m, Wb))
-
+            E.append(E[-1] & ahead(E[-1], 1 << j, never))
+        # run[t] == all ok in [t, t + r), r the bits of k taken so far; an
+        # empty run (r == 0) holds past the window's end too
+        run = jnp.ones((gb, m, Lb), dtype=bool)
+        empty = jnp.ones((gb, 1, 1), dtype=bool)
         for j in range(nbits):
-            s = 1 << j
-            # zero-pad (False) so every admissible offset slices in bounds
-            # — off before level j is at most 2^j - 1, so 2s covers it; a
-            # run reaching the padding correctly reads False
-            Ej = jnp.pad(E[j], ((0, 0), (0, 0), (0, 2 * s)))
-            bit = (ks >> j) & 1                          # (gb,)
-            sl = jax.vmap(row_slice)(Ej, off)
-            acc = acc & jnp.where(bit[:, None, None] > 0, sl, True)
-            off = off + bit * s
-        return jnp.swapaxes(acc, 1, 2)                   # (gb, Wb, m)
+            bit = ((ks >> j) & 1).astype(bool)[:, None, None]
+            run = jnp.where(bit, E[j] & ahead(run, 1 << j, empty), run)
+            empty = empty & ~bit
+        return jnp.swapaxes(run[:, :, :Wb], 1, 2)        # (gb, Wb, m)
 
     return jax.jit(scan)
 
 
 # eagerly constructed (cheap — jitting happens per key inside get): a lazy
-# ``global X; if X is None`` init is a check-then-act race under threads
-_SCAN_FNS = _BucketCache(_build_scan_fn, "scan")
+# ``global X; if X is None`` init is a check-then-act race under threads.
+# Uncapped: every key comes from pad8, W_LADDER, SHORT_K/LONG_K and the
+# device grid's ``alloc_len`` ladder, so the stage widths and grid spans a
+# process meets bound the cache, not a count: a few hundred programs per
+# (m, d), each 0.1-0.8 MB of code loaded on the device (a v5e compile).
+# An LRU cap below the keys a build stream reaches only traces and
+# reloads them again
+_SCAN_FNS = _BucketCache(_build_scan_fn, "scan", cap=None)
 
 
 def scan_fn_for(m: int, d: int, gb: int, Lb: int, Wb: int,

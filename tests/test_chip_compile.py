@@ -98,6 +98,25 @@ def test_xla_scan_bucket_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+@pytest.mark.parametrize("g, W, kmax, span", [
+    (24, WINDOW0, kernels.LONG_K, 256),     # a session's first window
+    (48, 256, kernels.LONG_K, 3072),        # the widest ad-hoc build bucket
+])
+def test_resident_scan_program_code_is_small_on_v5e(one_chip, g, W, kmax,
+                                                    span):
+    """Every scan program a process reaches stays loaded on the chip, so
+    its code must stay small: under 1 MiB (a per-row gather in the run
+    ladder made 2-9 MB a program)."""
+    gb, Lb, Wb = kernels.scan_buckets(g, W, kmax)
+    Tb = engine_jit.DeviceGrid.alloc_len(span)
+    fn = kernels.scan_fn_for(M_BUILD, D, gb, Lb, Wb, Tb)
+    s = functools.partial(_spec, one_chip)
+    compiled = fn.lower(s((M_BUILD, Tb, D), jnp.float32),
+                        s((), jnp.int32), s((), jnp.int32),
+                        s((gb, D), jnp.float32), s((gb,), jnp.int32)).compile()
+    assert compiled.memory_analysis().generated_code_size_in_bytes < 1 << 20
+
+
 def test_xla_machines_with_candidates_compiles_for_v5e(one_chip):
     fn = kernels._ELIG_FNS.get((D,))
     s = functools.partial(_spec, one_chip)
