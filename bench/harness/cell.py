@@ -114,11 +114,31 @@ def device_info(chips: int, require_tpu: bool) -> dict:
 
 
 def service_config(config: dict, **over):
+    """The service's configuration; its fairness groups are those of the
+    configuration's ``fairness`` section, unless ``service`` names them."""
     from repro.svc import ServiceConfig
 
     kw = dict(config["service"])
+    kw.setdefault("groups", tuple(check.fairness(config)["groups"]))
     kw.update(over)
     return ServiceConfig(**kw)
+
+
+def _fairness_fn(name: str):
+    """The program's fairness function ``f`` of a configuration's name."""
+    from repro.core.online import drf_fairness, slot_fairness
+
+    return {"slot": slot_fairness, "drf": drf_fairness}[name]
+
+
+def program_scheme(config: dict):
+    """The program's scheme as the configuration states it: its preset,
+    with the configuration's unfairness bound and fairness function."""
+    from repro.sim.cluster import scheme
+
+    fair = check.fairness(config)
+    return scheme(config["scheme"], kappa=fair["kappa"],
+                  fairness=_fairness_fn(fair["f"]))
 
 
 def _delta(after: dict, before: dict) -> dict:
@@ -177,14 +197,15 @@ def _window(drv, seconds: float, trace: bool, log):
     return steps, time.perf_counter() - w0 - paused, tdir
 
 
-def _check_program(spec_, config: dict) -> None:
-    """The program runs as the configuration states: its dagps policy and
-    builder knobs are the configuration's."""
+def _check_program(core, config: dict) -> None:
+    """The program runs as the configuration states: its dagps policy,
+    builder knobs, unfairness bound, fairness function and the groups'
+    normalised shares in the built scheduler are the configuration's."""
     import inspect
 
     from repro.core.builder import build_schedule
 
-    mc = spec_.matcher
+    mc = core.smatcher.matcher.cfg
     pol = config["policy"]
     got = {"eta_m": mc.eta_m, "max_overbook": mc.max_overbook,
            "bundle_limit": mc.bundle_limit}
@@ -195,6 +216,18 @@ def _check_program(spec_, config: dict) -> None:
     if got != pol or not all(flags) or knobs != config["builder"]:
         raise ValueError(f"the program's dagps scheme ({got}, {flags}, "
                          f"{knobs}) is not the configuration's")
+    fair = check.fairness(config)
+    if (mc.kappa != fair["kappa"]
+            or mc.fairness is not _fairness_fn(fair["f"])):
+        raise ValueError(f"the program's kappa {mc.kappa!r} and fairness "
+                         f"{mc.fairness.__name__} are not the configuration's "
+                         f"kappa {fair['kappa']!r} and f {fair['f']!r}")
+    want = list(zip(fair["groups"], fair["shares"]))
+    got_shares = list(core.smatcher.matcher.deficits.share.items())
+    if got_shares != want:
+        raise ValueError(f"the program's normalised group shares "
+                         f"{dict(got_shares)} are not the configuration's "
+                         f"{dict(want)}")
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool,
@@ -221,7 +254,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
 
     from repro.core.engine import jit as jit_mod
     from repro.core.engine import kernels
-    from repro.sim.cluster import scheme
 
     from . import driver, trace as trace_mod, warm
     from .traffic import Traffic
@@ -232,8 +264,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     jax.monitoring.register_event_duration_secs_listener(clock)
     try:
         cfg = service_config(config)
-        spec_ = scheme(config["scheme"])
-        _check_program(spec_, config)
+        spec_ = program_scheme(config)
         w = mix["warm"]
         n_elig = warm.eligibility(cfg, int(w["candidates_max"]))
         n_scan = warm.scans(cfg.build_machines, cfg.d, w) \
@@ -243,6 +274,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         traffic = Traffic(mix, seed, config["job_scale"])
         drv = driver.Driver(traffic, cfg, spec_)
         try:
+            _check_program(drv.core, config)
             drv.run_until_virtual(traffic.warm_until())
             c0 = (clock.count, clock.secs)
             x0 = dict(kernels.XLA_STATS)
@@ -289,7 +321,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         ctl = check.numbers(drv, config, seed, control=True) \
             if control else None
         log(f"check: references took {time.perf_counter() - t_ref:.3f} s "
-            f"over {drv.steps} events and {len(drv.job_tasks)} jobs")
+            f"over {drv.steps} events and {len(drv.job_tasks)} jobs; "
+            f"must_serve_picks={nums['must_serve_picks']} "
+            f"deficit_peak={nums['deficit_peak']!r} (of kappa*C)")
     finally:
         jax.monitoring.unregister_event_duration_listener(clock)
     return _result(bench, wl, trace, run_rec, nums, drv, device, ctl)

@@ -14,6 +14,11 @@ the pace of the replay cannot change a decision, and a wall-clock window
 over the replay measures the fastest pace at which the scheduler keeps
 up with this cluster.
 
+Each job is submitted in its fairness group, the arrival's ``group``:
+the traffic draws it from the mix's ``jobs.tenants`` (group 0 where the
+mix has none), and the configuration's ``fairness`` section lists the
+groups the service is told of (`ServiceConfig.groups`).
+
 Each handled event can be timed (`Driver.step` returns its record): the
 wall time from taking the event off the heap to the return of the
 service's pump that handled it, whether that pump ran a wave, and, for a
@@ -54,6 +59,12 @@ class Driver:
                                connect)
         from repro.svc.agent import VirtualAgent
 
+        self.arrivals = traffic.arrivals()
+        unknown = {a.group for a in self.arrivals} - set(cfg.groups)
+        if unknown:
+            raise ValueError(f"the traffic submits to groups "
+                             f"{sorted(unknown)} the configuration does not "
+                             f"list ({list(cfg.groups)})")
         self._DAG = DAG
         self._kernels = kernels
         self.traffic = traffic
@@ -77,7 +88,6 @@ class Driver:
         except BaseException:
             self.svc.close()
             raise
-        self.arrivals = traffic.arrivals()
         self._counter = itertools.count()
         self.events: list = []
         for k, a in enumerate(self.arrivals):
@@ -89,10 +99,11 @@ class Driver:
             heapq.heappush(self.events, (period * (m + 1) / n,
                                          next(self._counter), _HB, m))
         self.seen_jobs: set[int] = set()
-        #: task arrays and traffic keys of the service's jobs, in submit
-        #: order (= job id)
+        #: task arrays, traffic keys and fairness groups of the service's
+        #: jobs, in submit order (= job id)
         self.job_tasks: list = []
         self.job_keys: list[int] = []
+        self.job_groups: list[int] = []
         #: job results as the client receives them
         self.results: list = []
         #: (job, task) -> the time its agent finishes it
@@ -122,10 +133,11 @@ class Driver:
             tk = self.traffic.tasks(a)
             self.job_tasks.append(tk)
             self.job_keys.append(a.key)
+            self.job_groups.append(a.group)
             dag = self._DAG(duration=tk.duration, demand=tk.demand,
                             stage_of=tk.stage_of, parents=tk.parents,
                             name=tk.name)
-            self.client.submit(dag, group=0, t=t_now)
+            self.client.submit(dag, group=a.group, t=t_now)
         elif code == _DONE:
             m, lease = arg
             self.agents[m].complete(lease, t_now)

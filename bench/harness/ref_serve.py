@@ -20,10 +20,24 @@ scheduled), and decides every heartbeat wave itself:
   * a granted task runs its duration stretched by its machine's largest
     network or disk load after the grant, if over 1.
 
+Fairness follows the paper's bounded unfairness (§5): one deficit
+counter per fairness group of the configuration, kept in the ledger's
+precision and starting at 0.  After each pick every group ``g`` gains
+``share_g * f(demand)`` and the picked task's group loses ``f(demand)``,
+where ``f`` is 1 for slot fairness and the task's largest demand share
+(of a machine of capacity 1) for DRF, and shares are normalised to sum
+to 1.  Before each pick, if the most deprived group (the largest
+deficit) is at least ``kappa * C`` behind (``C``: the cluster's
+machines) and has a task that could be picked on this machine, only its
+tasks can be; the fit-before-overbook rule then applies among them.  The
+text leaves ties open: the most deprived group is the first of the
+largest deficits in the configuration's order of groups, and when that
+group has nothing to pick here, the others pick as usual (the next most
+deprived group is not forced).  One counter serves the whole cluster,
+whatever the number of matcher shards.
+
 Each job's priScore is an input: the builder's answer, which
-`ref_build` checks on its own.  Fairness across groups is not modelled:
-the configuration has one group, for which the deficit counters never
-bind.  Imports nothing of the program.
+`ref_build` checks on its own.  Imports nothing of the program.
 """
 
 from __future__ import annotations
@@ -37,18 +51,22 @@ EPS = 1e-9
 
 
 class Policy:
-    def __init__(self, p: dict):
+    def __init__(self, p: dict, fairness: dict):
         self.expose = int(p["expose_per_job"])
         self.eta_m = float(p["eta_m"])
         self.slack = float(p["max_overbook"]) - 1.0
         self.bundle = int(p["bundle_limit"])
         self.rigid = list(p["rigid_dims"])
         self.fungible = list(p["fungible_dims"])
+        self.groups = list(fairness["groups"])
+        self.shares = [float(s) for s in fairness["shares"]]   # sum to 1
+        self.kappa = float(fairness["kappa"])
+        self.drf = fairness["f"] == "drf"
 
 
 class _Job:
-    def __init__(self, tk, pri, t: float):
-        self.tk, self.pri, self.arrival = tk, pri, t
+    def __init__(self, tk, pri, t: float, group: int):
+        self.tk, self.pri, self.arrival, self.group = tk, pri, t, group
         n = tk.n
         self.pending = np.array([len(p) for p in tk.parents])
         kids: list[list[int]] = [[] for _ in range(n)]
@@ -66,10 +84,10 @@ class Cluster:
     """The reference scheduler and its cluster, stepped like the driver."""
 
     def __init__(self, traffic, n_machines: int, d: int, period: float,
-                 policy: Policy, pri_of, ledger=np.float64):
+                 policy: Policy, pri: list, ledger=np.float64):
         self.traffic = traffic
         self.pol = policy
-        self.pri_of = pri_of          # job id -> priScore
+        self.pri = pri                # job id -> priScore
         self.period = period
         #: free capacity per machine, in the configuration's precision
         self.avail = np.ones((n_machines, d), dtype=ledger)
@@ -85,6 +103,18 @@ class Cluster:
         self.leases: dict[int, tuple[int, int, int]] = {}
         self.next_lease = 1
         self.ema_score = self.ema_srpt = 1.0
+        #: the groups' shares and deficit counters, in the configuration's
+        #: order and the ledger's precision (scalars: a group or a few)
+        self.slot = {g: k for k, g in enumerate(policy.groups)}
+        self.ledger = ledger
+        self.share = [ledger(x) for x in policy.shares]
+        self.deficit = [ledger(0.0)] * len(policy.groups)
+        self.must_at = policy.kappa * float(n_machines)
+        #: picks made while a group was owed service, and the largest
+        #: deficit any group reached
+        self.must_serve_picks = 0
+        self.deficit_peak = 0.0
+        self.overrun = False
         self.placements: list[tuple[float, int, int, int]] = []
         self.results: list[tuple[int, float, float]] = []
         #: (job, task) -> the time its machine finishes it
@@ -100,7 +130,8 @@ class Cluster:
         if code == _ARR:
             a = self.arrivals[arg]
             j = len(self.jobs)
-            self.jobs.append(_Job(self.traffic.tasks(a), self.pri_of(j), t))
+            self.jobs.append(_Job(self.traffic.tasks(a), self.pri[j], t,
+                                  self.slot[a.group]))
             self.live.append(j)
             dirty = True
         elif code == _DONE:
@@ -118,7 +149,13 @@ class Cluster:
                 self._push(t_done, _DONE, lease)
 
     def run_steps(self, n: int) -> None:
+        """Handle ``n`` events, or stop short at an arrival past the jobs
+        whose priScore is known (``overrun``): the replay has then left
+        the run's own order of events, so the decisions already differ."""
         for _ in range(n):
+            if self.events[0][2] == _ARR and len(self.jobs) == len(self.pri):
+                self.overrun = True
+                return
             self.step()
 
     def _settle(self, lease: int, t: float) -> None:
@@ -156,6 +193,7 @@ class Cluster:
         pri = np.array([self.jobs[j].pri[i] for j, i in rows],
                        dtype=np.float64)
         srpt = np.array([self.jobs[j].srpt for j in job], dtype=np.float64)
+        grp = np.array([self.jobs[j].group for j in job])
         av = self.avail
         # which machines could take a task as the wave starts (a machine's
         # free capacity changes only when the wave reaches it)
@@ -175,7 +213,7 @@ class Cluster:
                 break
             if not (can[:, m] & active).any():
                 continue
-            for i in self._match(av[m], dem, pri, srpt, active):
+            for i in self._match(av[m], dem, pri, srpt, grp, active):
                 active[i] = False
                 j, task = int(job[i]), int(tid[i])
                 av[m] -= dem[i]
@@ -191,7 +229,7 @@ class Cluster:
                 granted.append((now + expected, m, lease))
         return granted
 
-    def _match(self, row, dem, pri, srpt, active) -> list[int]:
+    def _match(self, row, dem, pri, srpt, grp, active) -> list[int]:
         """Tasks picked for one machine, in pick order."""
         pol = self.pol
         free = row.astype(np.float64).copy()
@@ -203,6 +241,12 @@ class Cluster:
             over = (~fits & (dem[:, rig] <= free[rig] + EPS).all(axis=1)
                     & (dem[:, fun] <= free[fun] + pol.slack + EPS).all(axis=1))
             ok = (fits | over) & ~taken
+            most = max(self.deficit)
+            if most >= self.must_at:
+                mine = ok & (grp == self.deficit.index(most))
+                if mine.any():
+                    ok = mine
+                    self.must_serve_picks += 1
             if not ok.any():
                 break
             pos = np.clip(free, 0.0, None)
@@ -225,7 +269,18 @@ class Cluster:
             self.ema_srpt = (1 - a) * self.ema_srpt + a * max(float(srpt[i]), 1e-12)
             free -= dem[i]
             np.clip(free, 0.0, None, out=free)
+            self._allocated(int(grp[i]), dem[i])
         return picked
+
+    def _allocated(self, g: int, demand: np.ndarray) -> None:
+        """Every group gains its share of the pick's weight; the picked
+        task's group pays the weight."""
+        w = self.ledger(demand.max() if self.pol.drf else 1.0)
+        d = self.deficit
+        for k, share in enumerate(self.share):
+            d[k] = d[k] + share * w
+        d[g] = d[g] - w
+        self.deficit_peak = max(self.deficit_peak, float(max(d)))
 
     def eta_m_now(self) -> float:
         return self.pol.eta_m * self.ema_score / max(self.ema_srpt, 1e-12)

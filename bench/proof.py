@@ -7,8 +7,8 @@ Runs the cell, in one process (set-up and compiles are paid once), on
 each seed as the benchmark runs it, and prints one JSON line per run with
 the numbers compared.  On the seeds of ``--control-seeds`` it also reads
 the control: the references computed one precision below the
-configuration's (the scheduler's ledger in float32, the builder's space
-in float16) put in the program's place and compared like its answers.
+configuration's (the scheduler's ledger and deficit counters in
+float32, the builder's space in float16) put in the program's place and compared like its answers.
 The limits in ``bench/limits.json`` are set between the largest sound
 reading and the smallest control reading.  The benchmark's own runs never
 read the control.
